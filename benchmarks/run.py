@@ -1068,6 +1068,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     global SMOKE
     SMOKE = args.smoke
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sections = dict(SECTIONS)
     if not args.skip_paper:
         sections.update(paper_sections())

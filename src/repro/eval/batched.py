@@ -783,24 +783,21 @@ class BatchedHWEvaluator:
             self.backend = "numpy"
             return
         if backend == "auto":
-            try:
-                import jax
-                # measured dispatch (DESIGN.md 17): cached race winner for
-                # this (platform, shape) neighbourhood if present, else the
-                # static rule (pallas shift-add datapath on TPU, jnp's
-                # int32 dot_general elsewhere)
-                from repro import tune
-                mlp, n = self._mlp, self.n_val
-                x, lab = self._x[:n], self._labels[:n]
-                backend = tune.decide(
-                    "bhw_backend", shape=(n,) + self._x.shape[1:],
-                    dtype="int64", candidates=("numpy", "jnp", "pallas"),
-                    heuristic=("pallas" if jax.default_backend() == "tpu"
-                               else "jnp"),
-                    measure=lambda: tune.bhw_backend_thunks(mlp, x, lab))
-            except Exception:                              # pragma: no cover
-                self.backend = "numpy"
-                return
+            import jax
+            # measured dispatch (DESIGN.md 17): cached race winner for
+            # this (platform, shape) neighbourhood if present, else the
+            # static rule (pallas shift-add datapath on TPU, jnp's int32
+            # dot_general elsewhere).  An error here raises: a device
+            # backend is never silently swapped for the host.
+            from repro import tune
+            mlp, n = self._mlp, self.n_val
+            x, lab = self._x[:n], self._labels[:n]
+            backend = tune.decide(
+                "bhw_backend", shape=(n,) + self._x.shape[1:],
+                dtype="int64", candidates=("numpy", "jnp", "pallas"),
+                heuristic=("pallas" if jax.default_backend() == "tpu"
+                           else "jnp"),
+                measure=lambda: tune.bhw_backend_thunks(mlp, x, lab))
         self.backend = backend
         if not int32_safe_bound(self._mlp):
             self._demote("weights exceed the int32-safe accumulator bound")
@@ -1064,32 +1061,23 @@ class QSweepEvaluator:
         self.qchunk = int(qchunk)
         self.stats = {"eval_calls": 0, "networks": 0, "demoted": 0}
 
-        if backend in ("auto", "jnp", "pallas"):
-            try:
-                import jax
-                jax.devices()
-                if backend == "auto":
-                    # measured dispatch (DESIGN.md 17): a cached race winner
-                    # if one exists for this (platform, shape) neighbourhood,
-                    # else the static rule — on CPU hosts the stacked
-                    # BLAS-float64 path (exact below 2^53) beats XLA's int32
-                    # matmuls (DESIGN.md 10), accelerators get the jnp tier
-                    from repro import tune
-                    xs, ys = x_val_int, labels
-                    self.backend = tune.decide(
-                        "qsweep_backend", shape=x_val_int.shape,
-                        dtype="int64",
-                        candidates=("numpy", "jnp", "pallas"),
-                        heuristic=("numpy"
-                                   if jax.default_backend() == "cpu"
-                                   else "jnp"),
-                        measure=lambda: tune.qsweep_backend_thunks(xs, ys))
-                else:
-                    self.backend = backend    # jnp, or the digit-plane
-            except Exception:                 # pallas sweep mode (11.4)
-                self.backend = "numpy"        # pragma: no cover
-        else:
-            self.backend = "numpy"
+        if backend == "auto":
+            import jax
+            # measured dispatch (DESIGN.md 17): a cached race winner if one
+            # exists for this (platform, shape) neighbourhood, else the
+            # static rule — on CPU hosts the stacked BLAS-float64 path
+            # (exact below 2^53) beats XLA's int32 matmuls (DESIGN.md 10),
+            # accelerators get the jnp tier.  An error here raises.
+            from repro import tune
+            xs, ys = x_val_int, labels
+            backend = tune.decide(
+                "qsweep_backend", shape=x_val_int.shape, dtype="int64",
+                candidates=("numpy", "jnp", "pallas"),
+                heuristic=("numpy" if jax.default_backend() == "cpu"
+                           else "jnp"),
+                measure=lambda: tune.qsweep_backend_thunks(xs, ys))
+        # numpy, jnp, or the digit-plane pallas sweep mode (11.4)
+        self.backend = backend
 
         self._n_shards = 1
         if shard and self.backend != "numpy":

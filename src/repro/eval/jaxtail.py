@@ -269,7 +269,7 @@ class JaxState:
             return outs
 
         if sharded:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             row, rep = P("data"), P()
             in_specs = (tuple([row] * len(ev._a)),
@@ -277,7 +277,7 @@ class JaxState:
                         tuple([rep] * n_layers), tuple([rep] * n_layers),
                         row, row, rep, rep, rep, rep, rep, rep, rep, rep, rep)
             core = shard_map(core, mesh=ev._mesh, in_specs=in_specs,
-                             out_specs=(rep,) * 6, check_rep=False)
+                             out_specs=(rep,) * 6, check_vma=False)
         return jax.jit(core)
 
     def _build_chain(self, k: int):
@@ -347,7 +347,7 @@ class JaxState:
             return counts, flags
 
         if sharded:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             row, rep = P("data"), P()
             in_specs = (tuple([row] * len(ev._a)),
@@ -355,7 +355,7 @@ class JaxState:
                         tuple([rep] * n_layers), tuple([rep] * n_layers),
                         row, row, rep, rep, rep, rep, rep)
             core = shard_map(core, mesh=ev._mesh, in_specs=in_specs,
-                             out_specs=(rep, rep), check_rep=False)
+                             out_specs=(rep, rep), check_vma=False)
         return jax.jit(core)
 
     def _build(self, k: int, b_sz: int, use_pallas: bool,
@@ -454,7 +454,7 @@ class JaxState:
 
         core = spec_core if kind == "spec" else core
         if sharded:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             row, rep = P("data"), P()
             n_acc = len(ev._acc)
@@ -464,7 +464,7 @@ class JaxState:
                         tuple([rep] * (n_layers - k - 2)) if use_pallas
                         else (), rep, rep, rep, rep)
             core = shard_map(core, mesh=ev._mesh, in_specs=in_specs,
-                             out_specs=rep, check_rep=False)
+                             out_specs=rep, check_vma=False)
         return jax.jit(core)
 
 
@@ -557,11 +557,11 @@ class QSweepJax:
             return counts
 
         if sharded:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             row, rep = P("data"), P()
             in_specs = (row, row, row, rep, tuple([rep] * n_layers),
                         tuple([rep] * n_layers))
             core = shard_map(core, mesh=ev._mesh, in_specs=in_specs,
-                             out_specs=rep, check_rep=False)
+                             out_specs=rep, check_vma=False)
         return jax.jit(core)

@@ -62,7 +62,7 @@ def pow2_int(delta):
 
 
 def _attn_kernel(table_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
-                 m_ref, l_ref, acc_ref, *, nb, bs, G, window, scale):
+                 m_ref, l_ref, acc_ref, *, nb, bs, window, scale):
     # grid (B, nb): b = slot row, j = logical KV block (innermost — the
     # (m, l, acc) scratch carries across j and is reset at j == 0)
     b = pl.program_id(0)
@@ -83,11 +83,9 @@ def _attn_kernel(table_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, 0]                                   # (Hq, D)
+        qg = q_ref[0]                                     # (Hkv, G, D)
         k = k_ref[0]                                      # (bs, Hkv, D)
         v = v_ref[0]
-        hkv, d = k.shape[1], k.shape[2]
-        qg = q.reshape(hkv, G, d)
         # Base-2 online softmax with the running max quantized to integers:
         # scores are scaled by log2(e) up front, the carried max is
         # ceil()'d, and the rescale factor pow2_int(m_prev - m_new) is an
@@ -114,8 +112,7 @@ def _attn_kernel(table_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == nb - 1)
     def _flush():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)[..., None]
-        o_ref[0, 0] = out.reshape(o_ref.shape[2], o_ref.shape[3]).astype(
-            o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @partial(jax.jit, static_argnames=("window", "interpret"))
@@ -126,7 +123,11 @@ def paged_attention_kernel(q, k_pool, v_pool, table, cache_len, *,
     wrapper clamps the unallocated-sentinel NB and builds the
     revisit-last-block effective table); cache_len: (B,) int32 valid
     lengths.  Returns (B, 1, Hq, D) in q.dtype — bit-identical to
-    ``paged_decode_attention_ref`` on the same inputs."""
+    ``paged_decode_attention_ref`` on the same inputs.
+
+    The GQA grouping (Hq -> (Hkv, G)) happens here, outside the kernel: a
+    reshape that splits the sublane dim inside the body is a shape cast
+    Mosaic refuses for bf16 at group sizes such as G = 7 (qwen2-0.5b)."""
     B, _, Hq, D = q.shape
     NB, bs, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     nb = table.shape[1]
@@ -135,27 +136,24 @@ def paged_attention_kernel(q, k_pool, v_pool, table, cache_len, *,
     cache_len = cache_len.astype(jnp.int32)
     kv_spec = pl.BlockSpec((1, bs, Hkv, D),
                            lambda b, j, tref, cref: (tref[b, j], 0, 0, 0))
+    q_spec = pl.BlockSpec((1, Hkv, G, D),
+                          lambda b, j, tref, cref: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, Hq, D),
-                         lambda b, j, tref, cref: (b, 0, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, Hq, D),
-                               lambda b, j, tref, cref: (b, 0, 0, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G), jnp.float32),
             pltpu.VMEM((Hkv, G), jnp.float32),
             pltpu.VMEM((Hkv, G, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        partial(_attn_kernel, nb=nb, bs=bs, G=G, window=window,
+    out = pl.pallas_call(
+        partial(_attn_kernel, nb=nb, bs=bs, window=window,
                 scale=LOG2E / np.sqrt(D)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(table, cache_len, q, k_pool, v_pool)
+    )(table, cache_len, q.reshape(B, Hkv, G, D), k_pool, v_pool)
+    return out.reshape(B, 1, Hq, D)

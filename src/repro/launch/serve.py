@@ -18,9 +18,30 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import Model, get_config
 from repro.runtime.serve import (ReferenceEngine, Request, ServeEngine,
                                  summarize)
+
+
+def build_engine(cfg, params, *, engine: str = "paged", max_batch: int = 4,
+                 max_context: int = 128, quantized: bool = False,
+                 quant_bits: int = 8, temperature: float = 0.0,
+                 admission: str = "truncate", **paged):
+    """The launcher's engine for ``cfg``: ``ServeEngine`` for the standard-KV
+    families (``paged`` carries its remaining options, e.g.
+    ``kv_block_size``, ``decode_kernel``, ``prefill_batch``), the retained
+    ``ReferenceEngine`` on request or for any other family.  EOS is off
+    (``eos_id=-1``), so every request runs to its ``max_new_tokens``."""
+    if engine == "reference" or cfg.family not in ("dense", "moe"):
+        return ReferenceEngine(cfg, params, max_batch=max_batch,
+                               max_context=max_context, eos_id=-1,
+                               quantized=quantized, temperature=temperature,
+                               admission=admission)
+    return ServeEngine(cfg, params, max_batch=max_batch,
+                       max_context=max_context, eos_id=-1,
+                       quantized=quantized, quant_bits=quant_bits,
+                       temperature=temperature, admission=admission, **paged)
 
 
 def main(argv=None):
@@ -66,30 +87,24 @@ def main(argv=None):
                     help="shard_map the decode step over all devices")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    if args.engine == "reference" or cfg.family not in ("dense", "moe"):
-        eng = ReferenceEngine(cfg, params, max_batch=args.batch,
-                              max_context=args.context, eos_id=-1,
-                              quantized=args.quantized,
-                              temperature=args.temperature,
-                              admission=args.admission)
-    else:
-        eng = ServeEngine(cfg, params, max_batch=args.batch,
-                          max_context=args.context, eos_id=-1,
-                          quantized=args.quantized, quant_bits=args.bits,
-                          temperature=args.temperature,
-                          prefill_chunk=args.prefill_chunk,
-                          prefill_batch=args.prefill_batch,
-                          kv_block_size=args.kv_block_size,
-                          kv_gather=args.kv_gather,
-                          decode_kernel=args.decode_kernel,
-                          admission=args.admission,
-                          data_parallel=args.data_parallel,
-                          tensor_parallel=args.tensor_parallel)
+    eng = build_engine(cfg, params, engine=args.engine,
+                       max_batch=args.batch, max_context=args.context,
+                       quantized=args.quantized, quant_bits=args.bits,
+                       temperature=args.temperature,
+                       admission=args.admission,
+                       prefill_chunk=args.prefill_chunk,
+                       prefill_batch=args.prefill_batch,
+                       kv_block_size=args.kv_block_size,
+                       kv_gather=args.kv_gather,
+                       decode_kernel=args.decode_kernel,
+                       data_parallel=args.data_parallel,
+                       tensor_parallel=args.tensor_parallel)
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i,
                     prompt=rng.integers(0, cfg.vocab, args.prompt_len)

@@ -307,7 +307,7 @@ class ServeEngine:
 
         if not data_parallel:
             return jax.jit(step, donate_argnums=(1,))
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         if mesh is None:
             mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -325,7 +325,7 @@ class ServeEngine:
         fn = shard_map(step, mesh=mesh,
                        in_specs=(rep, row, P("data", None), P("data")),
                        out_specs=(P("data", None, None), row),
-                       check_rep=False)
+                       check_vma=False)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _build_tp_decode(self, deq, mesh):
@@ -348,7 +348,7 @@ class ServeEngine:
         is what the subprocess test asserts.
         """
         import dataclasses as _dc
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         cfg = self.cfg
         if mesh is None:
@@ -386,7 +386,7 @@ class ServeEngine:
         if self.kv_block_size:
             in_specs += (P(),)
         fn = shard_map(step, mesh=mesh, in_specs=in_specs,
-                       out_specs=(P(), head), check_rep=False)
+                       out_specs=(P(), head), check_vma=False)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _draw_one(self, rid, step, logits):
@@ -598,15 +598,15 @@ class ServeEngine:
             rows = int(np.sum(-(-clen // bs) * bs))
         return float(rows) * self._kv_row_bytes
 
-    def _decode_step(self, now):
-        """One decode token for EVERY decoding slot in a single fixed-shape
-        dispatch.  Idle/prefilling slots ride along as dummy rows: their
-        write position is their own next-write index, so the garbage they
-        deposit is always overwritten before the slot length reaches it."""
+    def decode_inputs(self):
+        """The next decode dispatch's inputs: ``(active, rids, steps, args)``
+        with ``args`` = (params, cache, tokens, positions[, block table]) as
+        the jitted decode step takes them, or None when no slot decodes.
+        Reserves the blocks the fed tokens' KV lands in."""
         active = [slot for slot, st in self.slots.items()
                   if st.phase == "decode"]
         if not active:
-            return []
+            return None
         B = self.max_batch
         toks = np.zeros((B, 1), np.int32)
         pos = np.minimum(self.cache.lengths.copy(), self.max_context - 1)
@@ -625,6 +625,20 @@ class ServeEngine:
                 jnp.asarray(pos, jnp.int32))
         if self.kv_block_size:
             args += (jnp.asarray(self.cache.block_table),)
+        return active, rids, steps, args
+
+    def _decode_step(self, now):
+        """One decode token for EVERY decoding slot in a single fixed-shape
+        dispatch.  Idle/prefilling slots ride along as dummy rows: their
+        write position is their own next-write index, so the garbage they
+        deposit is always overwritten before the slot length reaches it."""
+        inputs = self.decode_inputs()
+        if inputs is None:
+            return []
+        active, rids, steps, args = inputs
+        # host copy of the dispatched positions (active slots sit below
+        # max_context - 1 by the admission cap, so the clamp is theirs too)
+        pos = np.minimum(self.cache.lengths, self.max_context - 1)
         t0 = time.time()
         lg, self.cache.data = self._decode(*args)
         lg = np.asarray(lg)[:, 0]

@@ -328,7 +328,7 @@ print("SHARD-OK")
 
 
 def test_shard_map_data_parallel():
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))

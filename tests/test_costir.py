@@ -325,7 +325,7 @@ def test_tm_chain_device_shard_map_parity():
     import os
     import subprocess
     import sys
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))

@@ -107,7 +107,10 @@ def test_csd_expand_matches_scalar_recoder():
 def test_csd_expand_old_import_path_removed():
     # the PR 3 deprecation shim is gone: the kernel module no longer
     # exports csd_expand at all — repro.kernels is the only import path
-    from repro.kernels import csd_matvec as kernel_mod
+    # the package re-exports the csd_matvec *function* under the module's
+    # name, so fetch the module itself from the import system
+    import importlib
+    kernel_mod = importlib.import_module("repro.kernels.csd_matvec")
     assert not hasattr(kernel_mod, "csd_expand")
     assert "csd_expand" not in kernel_mod.__all__
     with pytest.raises(ImportError):

@@ -146,7 +146,7 @@ def test_grad_compression_numerics():
 
 def test_compressed_psum_shardmap():
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import compressed_psum
     mesh = jax.make_mesh((1,), ("data",))

@@ -391,7 +391,7 @@ print("DP-OK")
 
 def test_data_parallel_decode_parity():
     """shard_map decode over 4 forced host devices == single-device greedy."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
@@ -592,7 +592,7 @@ def test_tensor_parallel_decode_parity():
     token streams bit-identical to the single-device route — contiguous,
     block-paged, block-paged + fused kernel, and the data_parallel+block
     reroute (psum re-associates logits, so parity is on TOKENS)."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))

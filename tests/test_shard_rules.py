@@ -10,8 +10,16 @@ from repro import shard
 from repro.launch.specs import cache_struct, input_specs, param_structs
 from repro.nn.types import SHAPES, applicable_shapes, get_config, list_configs
 
-MESHES = [AbstractMesh((("data", 16), ("model", 16))),
-          AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))]
+MESH_SHAPES = {"16x16": ((16, 16), ("data", "model")),
+               "2x16x16": ((2, 16, 16), ("pod", "data", "model"))}
+
+
+@pytest.fixture
+def mesh(request):
+    """The production mesh named by the (indirect) parameter, built when the
+    test runs rather than at import."""
+    shape, axes = MESH_SHAPES[request.param]
+    return AbstractMesh(shape, axes)
 
 
 def _axis_size(mesh, axis):
@@ -35,7 +43,7 @@ def _check_tree(mesh, spec_tree, sds_tree):
 
 
 @pytest.mark.parametrize("arch", list_configs())
-@pytest.mark.parametrize("mesh", MESHES, ids=["16x16", "2x16x16"])
+@pytest.mark.parametrize("mesh", list(MESH_SHAPES), indirect=True)
 def test_param_specs_divisible(arch, mesh):
     cfg = get_config(arch)
     p_sds = param_structs(cfg)
@@ -44,8 +52,8 @@ def test_param_specs_divisible(arch, mesh):
 
 @pytest.mark.parametrize("arch", ["qwen2.5-3b", "arctic-480b", "rwkv6-3b",
                                   "recurrentgemma-9b", "whisper-base"])
-def test_cache_and_batch_specs_divisible(arch):
-    mesh = MESHES[0]
+@pytest.mark.parametrize("mesh", ["16x16"], indirect=True)
+def test_cache_and_batch_specs_divisible(arch, mesh):
     cfg = get_config(arch)
     for s in applicable_shapes(cfg):
         b_sds = input_specs(cfg, s)
@@ -55,10 +63,10 @@ def test_cache_and_batch_specs_divisible(arch):
             _check_tree(mesh, shard.cache_specs(mesh, c_sds), c_sds)
 
 
-def test_tp_sharding_covers_big_params():
+@pytest.mark.parametrize("mesh", ["16x16"], indirect=True)
+def test_tp_sharding_covers_big_params(mesh):
     """The largest parameters must actually be sharded (not replicated) —
     arctic would not fit otherwise (DESIGN.md 4)."""
-    mesh = MESHES[0]
     cfg = get_config("arctic-480b")
     p_sds = param_structs(cfg)
     specs = shard.param_specs(mesh, p_sds)
