@@ -1,0 +1,58 @@
+"""A tiny benchmark tree for CPU tests: the real harness files, copied, plus
+a two-layer configuration, a small chat mix and one cell."""
+from __future__ import annotations
+
+import json
+import shutil
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parents[2]
+
+TINY_CONFIG = {
+    "name": "tiny-qwen2", "source": "test configuration", "arch": "qwen2-0.5b",
+    "num_hidden_layers": 2, "hidden_size": 64, "intermediate_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 512,
+    "rms_norm_eps": 1e-6, "rope_theta": 1e6, "qkv_bias": True,
+    "reduced": [],
+    "engine": {"kv_block_size": 16, "decode_kernel": "fused",
+               "prefill_batch": 2, "prefill_chunk": 32},
+}
+TINY_MIX = {
+    "arrivals": "poisson",
+    "prompt_len": {"dist": "lognormal", "median": 40, "sigma": 0.7,
+                   "min": 8, "max": 120},
+    "output_len": {"dist": "uniform", "min": 16, "max": 48},
+}
+# CPU readings at this size over ten seeds: sound runs 0.7e-4 to 3.0e-4, the
+# int8 control 3.0e-4 to 1.35e-3, so the two can meet here; the tests' seeds
+# read far from the limit (sound, seed 501: 0.7e-4; control, seed 4:
+# 1.35e-3).  The limits that decide a cell are set on the chip, at its size.
+TINY_CELL = {"max_batch": 4, "max_context": 256,
+             "load": {"rate_per_s": 4.0},
+             "limits": {"mean_logit_gap": 3.2e-4}}
+
+
+def e2e(name, unit, better="lower"):
+    return {"name": name, "unit": unit, "better": better, "bound": 0.1,
+            "source": "host_clock"}
+
+
+def make_tree(dst: Path, *, cell="tiny-qwen2.chat", per_layer=None) -> Path:
+    """Copy ``bench/`` to ``dst`` and add the tiny configuration and cell."""
+    shutil.copytree(REPO / "bench", dst / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (dst / "bench/configs/tiny-qwen2.json").write_text(json.dumps(TINY_CONFIG))
+    (dst / "bench/traffic/tinychat.json").write_text(json.dumps(TINY_MIX))
+    (dst / f"bench/cells/{cell}.json").write_text(json.dumps(TINY_CELL))
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    bench["configs"] = [{"name": "tiny-qwen2", "source": "test",
+                         "file": "bench/configs/tiny-qwen2.json",
+                         "reduced": [], "why": "CPU test"}]
+    bench["workloads"] = [{"name": cell, "config": "tiny-qwen2",
+                           "traffic": "tinychat", "chips": 1, "why": "test"}]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        m.pop("workloads", None)
+    if per_layer is not None:
+        bench["per_layer"] = per_layer
+    (dst / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+    return dst
