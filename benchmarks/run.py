@@ -529,7 +529,7 @@ def bench_serving():
     # replay clock, a FUSED-DECODE lane (Pallas paged-attention kernel +
     # Pallas gather — the routes CI's serving smoke covers), and the
     # LONG-PROMPT pair (prompts near max_context, dense vs fused — the
-    # KV-bytes-per-token claim)
+    # fused kernel's tokens/s claim, asserted on a TPU only)
     lane_cfgs = [dict(quant=q, rate=r, prefill_batch=1, kv_block_size=0)
                  for q in (False, True) for r in rates]
     lane_cfgs += [dict(quant=False, rate=top, prefill_batch=4,
@@ -566,7 +566,7 @@ def bench_serving():
         # drop the warmup from the aggregate counters so decode_tok_s
         # divides by replay-only decode wall time
         eng.stats.update(prefill_tokens=0, decode_tokens=0,
-                         prefill_s=0.0, decode_s=0.0, kv_bytes_read=0.0)
+                         prefill_s=0.0, decode_s=0.0)
         arrive = np.cumsum(rng.exponential(1.0 / rate, n_req))
         # long lanes replay prompts near max_context (every slot decodes
         # against a nearly full cache row); the others a short mixed batch
@@ -599,21 +599,17 @@ def bench_serving():
             name += f"/{kernel}"
         if long:
             name += "/long"
-        kv_per_tok = (eng.stats["kv_bytes_read"]
-                      / max(eng.stats["decode_tokens"], 1))
         rows.append((name, wall * 1e6,
                      f"decode_tok_s={s['decode_tok_s']:.1f};"
                      f"first_tok_p50_ms={s['p50_first_token_s']*1e3:.1f};"
                      f"first_tok_p99_ms={s['p99_first_token_s']*1e3:.1f};"
                      f"total_p50_ms={s['p50_total_s']*1e3:.1f};"
                      f"total_p99_ms={s['p99_total_s']*1e3:.1f};"
-                     f"kv_bytes_per_tok={kv_per_tok:.0f};"
                      f"done={s['done']}"))
         lanes.append({"quant": tag, "rate_rps": rate, "n_requests": n_req,
                       "prefill_batch": pb, "kv_block_size": bs,
                       "kv_gather": gather, "decode_kernel": kernel,
                       "long_prompts": bool(long),
-                      "kv_bytes_per_token": kv_per_tok,
                       "wall_s": wall, **s})
     # the batched-prefill claim: at the highest offered rate, ingesting up
     # to 4 chunks per step must beat the single-chunk head-of-line config
@@ -634,41 +630,12 @@ def bench_serving():
             "batched prefill must strictly improve p99 TTFT at the highest "
             f"arrival rate: pb4={batched['p99_first_token_s']:.4f}s vs "
             f"pb1={base['p99_first_token_s']:.4f}s")
-    # the fused-kernel claim at the LONG-PROMPT lane: decoding against
-    # nearly full cache rows, the fused route must read strictly fewer KV
-    # bytes per token than gather+dense (3x full-row traffic vs one pass
-    # over the actual blocks) — priced per layer via ServingCostSheet so
-    # the trajectory tooling can diff the ledgers
-    from repro.core.hwmodel import ServingCostSheet
-
-    def _kv_sheet(lane):
-        itemsize = 4                     # f32 KV cache (quant is W-only)
-        rowb = cfg.n_kv_heads * cfg.head_dim_ * 2 * itemsize
-        rows_tok = lane["kv_bytes_per_token"] / (cfg.n_layers * rowb)
-        sheet = ServingCostSheet(meta={
-            "kind": "decode_kv_read", "decode_kernel": lane["decode_kernel"],
-            "rows_per_token": rows_tok})
-        for i in range(cfg.n_layers):
-            sheet.add_layer(f"layer{i}/decode_kv_read", bits=8 * itemsize,
-                            k=int(round(rows_tok)),
-                            n=cfg.n_kv_heads * cfg.head_dim_ * 2,
-                            act_itemsize=0.0)
-        return sheet
-
+    # the fused-kernel claim at the LONG-PROMPT lane (nearly full cache
+    # rows): decode tokens/s at least gather+dense's
     long_dense = next(l for l in lanes if l["long_prompts"]
                       and l["decode_kernel"] == "dense")
     long_fused = next(l for l in lanes if l["long_prompts"]
                       and l["decode_kernel"] == "fused")
-    sh_d, sh_f = _kv_sheet(long_dense), _kv_sheet(long_fused)
-    rows.append(("serving/long_prompt_kv_bytes", 0.0,
-                 f"dense={sh_d.total_bytes():.0f}B/tok;"
-                 f"fused={sh_f.total_bytes():.0f}B/tok;"
-                 f"dense_tok_s={long_dense['decode_tok_s']:.1f};"
-                 f"fused_tok_s={long_fused['decode_tok_s']:.1f}"))
-    assert sh_f.total_bytes() < sh_d.total_bytes(), (
-        "fused paged decode must read strictly fewer KV bytes per token "
-        f"than gather+dense at the long-prompt lane: fused="
-        f"{sh_f.total_bytes():.0f} vs dense={sh_d.total_bytes():.0f}")
     if not SMOKE and jax.default_backend() == "tpu":
         # wall-clock claim only where the kernel compiles to Mosaic; on CPU
         # the fused lane runs the Pallas interpreter, which times the

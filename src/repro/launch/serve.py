@@ -8,7 +8,11 @@ continuous-batching-lite engine instead (any model family);
 ``--data-parallel`` shards the decode step over every visible device;
 ``--tensor-parallel`` shards heads + FFN instead (works with block
 paging); ``--decode-kernel fused`` runs decode attention straight from
-the KV block pool via the fused Pallas kernel.
+the KV block pool via the fused Pallas kernel.  ``--record`` keeps the
+paged engine's spans and counters (``engine.rec``) and prints their
+breakdown (:func:`repro.runtime.spans.breakdown`): device, logits copy,
+sampling and host time per step, KV and prefill fill.  The run's first
+steps compile, so serve enough requests that they do not dominate.
 """
 from __future__ import annotations
 
@@ -22,6 +26,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import Model, get_config
 from repro.runtime.serve import (ReferenceEngine, Request, ServeEngine,
                                  summarize)
+from repro.runtime.spans import breakdown
 
 
 def build_engine(cfg, params, *, engine: str = "paged", max_batch: int = 4,
@@ -81,6 +86,9 @@ def main(argv=None):
                     default="truncate")
     ap.add_argument("--deadline", type=float, default=None,
                     help="per-request queue deadline in seconds")
+    ap.add_argument("--record", action="store_true",
+                    help="keep the paged engine's spans and counters and "
+                         "print their breakdown")
     ap.add_argument("--engine", choices=("paged", "reference"),
                     default="paged")
     ap.add_argument("--data-parallel", action="store_true",
@@ -112,6 +120,8 @@ def main(argv=None):
                     max_new_tokens=args.max_new,
                     deadline_s=args.deadline)
             for i in range(args.requests)]
+    if isinstance(eng, ServeEngine):
+        eng.rec.on = args.record
     t0 = time.time()
     eng.run(reqs)
     wall = time.time() - t0
@@ -130,6 +140,12 @@ def main(argv=None):
               f"p99={s['p99_total_s']*1e3:.1f}ms; "
               f"done={s['done']} rejected={s['rejected']} "
               f"expired={s['expired']} truncated={s['truncated']}")
+        if args.record:
+            parts = breakdown(eng.rec.spans, eng.rec.counters)
+            print("record: " + " ".join(
+                f"{k}={'-' if v is None else f'{v:.3f}'}"
+                for k, v in parts.items())
+                + f" dropped={eng.rec.dropped}")
     for r in reqs[:3]:
         print(f"  req {r.rid}: {r.out_tokens}")
 

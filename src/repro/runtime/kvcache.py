@@ -302,3 +302,17 @@ class PagedKVCache:
                                             self.held_blocks(slot))
             self.block_table[slot] = self.n_blocks
         self._free.append(slot)
+
+    def report(self, rec, writing: int = 0) -> None:
+        """Count into the recorder ``rec`` the blocks held (of the pool;
+        block mode) and the live positions: the live slots' lengths plus
+        the ``writing`` positions the next dispatch adds, of what the live
+        slots reserve (their blocks, or whole rows in contiguous mode)."""
+        live = int(self.lengths[list(self.owner)].sum()) + writing
+        if self.block_table is None:
+            cap = len(self.owner) * self.max_context
+        else:
+            held = self.n_blocks - self.n_free_blocks
+            rec.count("kv.blocks_held", held, of=self.n_blocks)
+            cap = held * self.block_size
+        rec.count("kv.tokens_live", live, of=cap)

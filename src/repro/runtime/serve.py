@@ -36,7 +36,13 @@ The production engine (DESIGN.md 13).  ``ServeEngine`` replaces the seed's
   with actual per-slot lengths);
 * in-place cache updates: both jitted dispatches DONATE the KV-cache
   pytree (``donate_argnums``), so a decode step updates the pool's buffers
-  instead of allocating a second full-size copy.
+  instead of allocating a second full-size copy;
+* a record (:class:`repro.runtime.spans.Recorder`, ``engine.rec``): the
+  always-on decision log ``events``, and, while ``engine.rec.on`` is set,
+  the spans of every step (:data:`SPANS`) and of every request
+  (:data:`REQUEST_SPANS`) and counters of its work.  While recording, each
+  dispatch waits for the device before the logits' copy, so the two are
+  timed apart.
 
 With ``quantized=True`` the matmul weights serve as int8-PoT (repro.quant);
 dequantization happens INSIDE the jitted dispatches so the resident bytes
@@ -57,8 +63,23 @@ from repro.nn.types import ArchConfig
 from repro.quant import serving_ledger, serving_quant
 from repro.runtime import kvcache
 from repro.runtime.kvcache import ADMIT_REJECT, ADMIT_TRUNCATE, PagedKVCache
+from repro.runtime.spans import Recorder
 
-__all__ = ["ServeEngine", "ReferenceEngine", "Request", "summarize"]
+__all__ = ["ServeEngine", "ReferenceEngine", "Request", "summarize",
+           "SPANS", "REQUEST_SPANS"]
+
+#: the spans of one engine step: ``serve.step`` and its children, each also
+#: a ``jax.profiler.TraceAnnotation``.  ``*.device`` runs from the dispatch
+#: to the logits being ready, ``*.to_host`` is their copy to the host,
+#: ``*.emit`` the token loop with its callbacks and releases.
+SPANS = ("serve.step", "serve.expire", "serve.assign",
+         "prefill.inputs", "prefill.device", "prefill.to_host",
+         "prefill.sample", "prefill.emit",
+         "decode.inputs", "decode.device", "decode.to_host",
+         "decode.sample", "decode.emit")
+#: the phases of one request, on the engine's clock: arrival to a slot (or
+#: to expiry), the slot to the first token, the first token to release
+REQUEST_SPANS = ("request.queued", "request.prefill", "request.decode")
 
 
 @dataclass
@@ -126,6 +147,7 @@ class _Slot:
     n_prefilled: int = 0          # prompt tokens already ingested
     phase: str = "prefill"        # prefill -> decode
     assigned_s: float = 0.0
+    first_s: float = 0.0          # when its first token was sampled
     seq: int = 0                  # assignment sequence (prefill FIFO order)
 
 
@@ -251,12 +273,6 @@ class ServeEngine:
             deq = lambda t: t                                   # noqa: E731
         self.cache = PagedKVCache(self.model, max_batch, max_context,
                                   block_size=kv_block_size)
-        # analytic decode-attention KV traffic: bytes one logical cache row
-        # (K + V, every layer) occupies — priced per dispatch by
-        # _decode_kv_bytes into stats["kv_bytes_read"]
-        itemsize = jax.tree.leaves(self.cache.data)[0].dtype.itemsize
-        self._kv_row_bytes = (cfg.n_layers * cfg.n_kv_heads
-                              * cfg.head_dim_ * 2 * itemsize)
         self._decode = self._build_decode(deq, data_parallel,
                                           tensor_parallel, mesh)
         # donate_argnums=(1,): the cache pytree is consumed by every
@@ -279,15 +295,22 @@ class ServeEngine:
         self._draw = jax.jit(jax.vmap(self._draw_one))
         self.queue: deque = deque()        # FIFO admitted requests
         self.slots: dict = {}              # slot id -> _Slot
-        self.events: list = []             # (step, action, rid, slot)
+        self.rec = Recorder()              # off: the decision log only
         self._step_idx = 0
         self._seq = 0
+        # prefill_s / decode_s: time.monotonic from each dispatch to the
+        # end of its logits' copy (the *.device and *.to_host spans)
         self.stats = {"prefill_tokens": 0, "decode_tokens": 0,
                       "prefill_s": 0.0, "decode_s": 0.0,
-                      "prefill_chunks": 0, "prefill_dispatches": 0,
+                      "prefill_dispatches": 0,
                       "decode_steps": 0, "steps": 0,
                       "admitted": 0, "rejected": 0, "truncated": 0,
-                      "expired": 0, "finished": 0, "kv_bytes_read": 0.0}
+                      "expired": 0, "finished": 0}
+
+    @property
+    def events(self) -> list:
+        """The decision log: ``(step, action, rid, slot)`` tuples."""
+        return self.rec.events
 
     # ------------------------------------------------------------ dispatches
     def _build_decode(self, deq, data_parallel: bool, tensor_parallel: bool,
@@ -408,6 +431,24 @@ class ServeEngine:
                                      jnp.asarray(steps, jnp.uint32),
                                      jnp.asarray(logits)))
 
+    def _dispatch(self, phase: str, fn, args):
+        """Run the jitted ``fn`` (which returns logits and the new cache)
+        and copy the logits to the host: the host logits, and the seconds
+        from the dispatch to the copy's end.  While recording, the device
+        is waited for before the copy (``{phase}.device``), so the copy is
+        timed on its own (``{phase}.to_host``); ``np.asarray`` waits
+        anyway, so the total is the same."""
+        rec = self.rec
+        t0 = time.monotonic()
+        with rec.span(f"{phase}.device", start=t0):
+            logits, self.cache.data = fn(*args)
+            if rec.on:
+                jax.block_until_ready(logits)
+        with rec.span(f"{phase}.to_host") as copy:
+            logits = np.asarray(logits)
+        t1 = time.monotonic() if copy is None else copy.end
+        return logits, t1 - t0
+
     # ------------------------------------------------------------- frontend
     def _now(self, now):
         return self.clock() if now is None else now
@@ -450,13 +491,18 @@ class ServeEngine:
         t = self._now(now)
         self._step_idx += 1
         self.stats["steps"] += 1
-        self._expire(t)
-        self._assign(t)
-        # sub-steps get the RAW argument: with now=None they re-read the
-        # clock after their dispatch (t_first/t_done include dispatch wall
-        # time); with an injected now they stay in the caller's timebase
-        self._prefill_step(now)
-        return self._decode_step(now)
+        rec = self.rec
+        with rec.span("serve.step", step=self._step_idx):
+            with rec.span("serve.expire"):
+                self._expire(t)
+            with rec.span("serve.assign"):
+                self._assign(t)
+            # sub-steps get the RAW argument: with now=None they re-read
+            # the clock after their dispatch (t_first/t_done include
+            # dispatch wall time); with an injected now they stay in the
+            # caller's timebase
+            self._prefill_step(now)
+            return self._decode_step(now)
 
     def run(self, requests: list) -> list:
         """Serve a list of Requests to completion; returns them filled."""
@@ -482,6 +528,7 @@ class ServeEngine:
                 r.stats["queue_s"] = now - r.arrival_s
                 self.stats["expired"] += 1
                 self.events.append((self._step_idx, "expire", r.rid, None))
+                self.rec.add("request.queued", r.arrival_s, now, rid=r.rid)
 
     def _assign(self, now):
         while self.queue and self.cache.n_free:
@@ -492,6 +539,7 @@ class ServeEngine:
             self.slots[slot] = _Slot(req=r, assigned_s=now, seq=self._seq)
             self._seq += 1
             self.events.append((self._step_idx, "assign", r.rid, slot))
+            self.rec.add("request.queued", r.arrival_s, now, rid=r.rid)
 
     def _emit(self, r):
         """Fire the streaming callback for the token just appended."""
@@ -511,33 +559,34 @@ class ServeEngine:
                          if st.phase == "prefill")
         if not pending:
             return
+        rec = self.rec
         picked = [slot for _, slot in pending[:self.prefill_batch]]
         P, chunk = self.prefill_batch, self.prefill_chunk
-        toks = np.zeros((P, chunk), np.int32)
-        slots = np.zeros(P, np.int32)
-        offs = np.full(P, self.max_context, np.int32)   # dummies: all-drop
-        nval = np.ones(P, np.int32)
-        ns = []
-        for i, slot in enumerate(picked):
-            st = self.slots[slot]
-            r = st.req
-            n = min(chunk, len(r.prompt) - st.n_prefilled)
-            toks[i, :n] = r.prompt[st.n_prefilled:st.n_prefilled + n]
-            slots[i], offs[i], nval[i] = slot, st.n_prefilled, n
-            ns.append(n)
+        with rec.span("prefill.inputs"):
+            toks = np.zeros((P, chunk), np.int32)
+            slots = np.zeros(P, np.int32)
+            offs = np.full(P, self.max_context, np.int32)  # dummies: drop
+            nval = np.ones(P, np.int32)
+            ns = []
+            for i, slot in enumerate(picked):
+                st = self.slots[slot]
+                r = st.req
+                n = min(chunk, len(r.prompt) - st.n_prefilled)
+                toks[i, :n] = r.prompt[st.n_prefilled:st.n_prefilled + n]
+                slots[i], offs[i], nval[i] = slot, st.n_prefilled, n
+                ns.append(n)
+                if self.kv_block_size:
+                    self.cache.ensure(slot, st.n_prefilled + n)
+            args = (self.params, self.cache.data, jnp.asarray(toks),
+                    jnp.asarray(slots), jnp.asarray(offs),
+                    jnp.asarray(nval))
             if self.kv_block_size:
-                self.cache.ensure(slot, st.n_prefilled + n)
-        t0 = time.time()
-        args = (self.params, self.cache.data, jnp.asarray(toks),
-                jnp.asarray(slots), jnp.asarray(offs), jnp.asarray(nval))
-        if self.kv_block_size:
-            args += (jnp.asarray(self.cache.block_table),)
-        logits, self.cache.data = self._prefill(*args)
-        logits = np.asarray(logits)
-        dt = time.time() - t0
+                args += (jnp.asarray(self.cache.block_table),)
+            if rec.on:
+                rec.count("prefill.tokens", sum(ns), of=P * chunk)
+        logits, dt = self._dispatch("prefill", self._prefill, args)
         self.stats["prefill_s"] += dt
         self.stats["prefill_tokens"] += int(sum(ns))
-        self.stats["prefill_chunks"] += len(picked)
         self.stats["prefill_dispatches"] += 1
         done_rows = []
         for i, slot in enumerate(picked):
@@ -554,49 +603,24 @@ class ServeEngine:
         # last-valid-position logits (token index 0; EOS is deliberately NOT
         # checked here — the reference engine ignores a first-token EOS and
         # parity pins that behavior)
-        rows = np.array([i for i, _ in done_rows])
-        rids = np.array([self.slots[s].req.rid for _, s in done_rows])
-        nxt = self._sample(logits[rows], rids, np.zeros(len(rows), np.int64))
-        t_first = self._now(now)
-        for j, (i, slot) in enumerate(done_rows):
-            st = self.slots[slot]
-            r = st.req
-            r.out_tokens.append(int(nxt[j]))
-            self._emit(r)
-            r.stats["first_token_s"] = t_first - r.arrival_s
-            st.phase = "decode"
-            if len(r.out_tokens) >= r.stats["max_new_eff"]:
-                self._finish(slot, t_first)
-
-    def _decode_kv_bytes(self, pos) -> float:
-        """Analytic KV bytes one decode dispatch reads for its attention,
-        summed over every slot row in the fixed-shape batch (idle rows ride
-        along and their cache IS read).  Host-side pricing, not a
-        measurement — but it is exact for each route's access pattern:
-
-        * contiguous slab — the dense masked pass streams every slot's full
-          ``max_context`` row once;
-        * block pool, ``decode_kernel="dense"`` — gather reads the whole
-          table's blocks, writes the contiguous copy, and the dense pass
-          reads it back: 3x full-row traffic;
-        * ``"reference"`` — one pass over every table entry (the scan takes
-          all ``nb`` blocks, masked or not);
-        * ``"fused"`` — one pass over just ``ceil(len/bs)`` blocks per slot
-          (the effective-table remap collapses the masked tail into a
-          revisit), so bytes scale with the ACTUAL per-slot lengths.
-        """
-        C = self.max_context
-        clen = np.minimum(np.asarray(pos) + 1, C)
-        if not self.kv_block_size:
-            rows = C * clen.size
-        elif self.decode_kernel == "dense":
-            rows = 3 * C * clen.size
-        elif self.decode_kernel == "reference":
-            rows = C * clen.size
-        else:                                  # fused
-            bs = self.kv_block_size
-            rows = int(np.sum(-(-clen // bs) * bs))
-        return float(rows) * self._kv_row_bytes
+        with rec.span("prefill.sample"):
+            rows = np.array([i for i, _ in done_rows])
+            rids = np.array([self.slots[s].req.rid for _, s in done_rows])
+            nxt = self._sample(logits[rows], rids,
+                               np.zeros(len(rows), np.int64))
+        with rec.span("prefill.emit"):
+            t_first = self._now(now)
+            for j, (i, slot) in enumerate(done_rows):
+                st = self.slots[slot]
+                r = st.req
+                r.out_tokens.append(int(nxt[j]))
+                self._emit(r)
+                r.stats["first_token_s"] = t_first - r.arrival_s
+                st.first_s = t_first
+                rec.add("request.prefill", st.assigned_s, t_first, rid=r.rid)
+                st.phase = "decode"
+                if len(r.out_tokens) >= r.stats["max_new_eff"]:
+                    self._finish(slot, t_first)
 
     def decode_inputs(self):
         """The next decode dispatch's inputs: ``(active, rids, steps, args)``
@@ -632,37 +656,37 @@ class ServeEngine:
         dispatch.  Idle/prefilling slots ride along as dummy rows: their
         write position is their own next-write index, so the garbage they
         deposit is always overwritten before the slot length reaches it."""
-        inputs = self.decode_inputs()
+        rec = self.rec
+        with rec.span("decode.inputs"):
+            inputs = self.decode_inputs()
+            if inputs is not None and rec.on:
+                self.cache.report(rec, writing=len(inputs[0]))
         if inputs is None:
             return []
         active, rids, steps, args = inputs
-        # host copy of the dispatched positions (active slots sit below
-        # max_context - 1 by the admission cap, so the clamp is theirs too)
-        pos = np.minimum(self.cache.lengths, self.max_context - 1)
-        t0 = time.time()
-        lg, self.cache.data = self._decode(*args)
-        lg = np.asarray(lg)[:, 0]
-        dt = time.time() - t0
+        lg, dt = self._dispatch("decode", self._decode, args)
+        lg = lg[:, 0]
         self.stats["decode_s"] += dt
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += len(active)
-        self.stats["kv_bytes_read"] += self._decode_kv_bytes(pos)
-        nxt = self._sample(lg, rids, steps)
-        t_done = self._now(now)
-        finished = []
-        for slot in active:
-            st = self.slots[slot]
-            r = st.req
-            self.cache.lengths[slot] += 1     # the fed token's KV was written
-            tok = int(nxt[slot])
-            r.out_tokens.append(tok)
-            self._emit(r)
-            r.stats["decode_tokens"] = r.stats.get("decode_tokens", 0) + 1
-            r.stats["decode_s"] = r.stats.get("decode_s", 0.0) + dt
-            if tok == self.eos_id or \
-                    len(r.out_tokens) >= r.stats["max_new_eff"]:
-                finished.append(r)
-                self._finish(slot, t_done)
+        with rec.span("decode.sample"):
+            nxt = self._sample(lg, rids, steps)
+        with rec.span("decode.emit"):
+            t_done = self._now(now)
+            finished = []
+            for slot in active:
+                st = self.slots[slot]
+                r = st.req
+                self.cache.lengths[slot] += 1   # the fed token's KV written
+                tok = int(nxt[slot])
+                r.out_tokens.append(tok)
+                self._emit(r)
+                r.stats["decode_tokens"] = r.stats.get("decode_tokens", 0) + 1
+                r.stats["decode_s"] = r.stats.get("decode_s", 0.0) + dt
+                if tok == self.eos_id or \
+                        len(r.out_tokens) >= r.stats["max_new_eff"]:
+                    finished.append(r)
+                    self._finish(slot, t_done)
         return finished
 
     def _finish(self, slot, now):
@@ -677,6 +701,7 @@ class ServeEngine:
         self.cache.release(slot)
         self.stats["finished"] += 1
         self.events.append((self._step_idx, "release", r.rid, slot))
+        self.rec.add("request.decode", st.first_s, now, rid=r.rid)
 
 
 class ReferenceEngine:

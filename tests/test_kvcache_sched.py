@@ -117,6 +117,50 @@ def test_paged_cache_block_lifecycle():
     assert sorted(c._free_blocks) == [0, 1, 2, 3]
 
 
+@pytest.mark.parametrize("block_size", [4, 0])
+def test_paged_cache_counts_blocks_and_live_tokens(block_size):
+    """``report`` counts the blocks the slots hold and their live positions
+    (lengths plus the positions a dispatch writes) through admit, grow and
+    release."""
+    from repro.runtime.spans import Recorder
+    c = PagedKVCache(_FakeModel(), 3, 16, block_size=block_size)
+
+    def check(writing=0):
+        rec = Recorder()
+        rec.on = True
+        c.report(rec, writing=writing)
+        got = {k.name: (k.value, k.of) for k in rec.counters}
+        held = sum(len(c.held_blocks(s)) for s in c.owner)
+        live = sum(int(c.lengths[s]) for s in c.owner) + writing
+        want = {"kv.tokens_live": (live, held * block_size if block_size
+                                   else len(c.owner) * c.max_context)}
+        if block_size:
+            want["kv.blocks_held"] = (held, c.n_blocks)
+        assert got == want
+        return got
+
+    check()
+    a, b = c.alloc(1), c.alloc(2)                 # admit
+    check()
+    for slot, n in ((a, 3), (b, 5), (a, 4), (a, 9), (b, 16)):
+        c.ensure(slot, n)                          # grow, then write
+        check(writing=n - int(c.lengths[slot]))
+        c.lengths[slot] = n
+        check()
+    if block_size:
+        assert check()["kv.blocks_held"] == (3 + 4, c.n_blocks)
+    c.release(a)                                   # release
+    check()
+    d = c.alloc(3)                                 # reuses a's slot at 0
+    c.ensure(d, 2)
+    check(writing=2)
+    c.release(b)
+    c.release(d)
+    assert check() == {"kv.tokens_live": (0, 0),
+                       **({"kv.blocks_held": (0, c.n_blocks)}
+                          if block_size else {})}
+
+
 def _block_cache_fuzz(seed):
     """Random alloc/ensure/release storm on a block-mode cache: no physical
     block is ever held by two slots, free + held is always the whole pool,

@@ -30,6 +30,18 @@ def test_serve_launcher(capsys):
     assert "served 2 requests" in out
 
 
+def test_serve_launcher_record(capsys):
+    from repro.launch.serve import main
+    main(["--arch", "qwen2-0.5b", "--reduced", "--requests", "3",
+          "--prompt-len", "4", "--max-new", "3", "--batch", "2",
+          "--context", "16", "--kv-block-size", "8", "--record"])
+    out = capsys.readouterr().out
+    line = next(l for l in out.splitlines() if l.startswith("record: "))
+    parts = dict(p.split("=") for p in line[len("record: "):].split())
+    assert "-" not in parts.values() and parts.pop("dropped") == "0"
+    assert float(parts["decode_device_ms"]) > 0
+
+
 def test_serve_launcher_fused_tensor_parallel(capsys):
     from repro.launch.serve import main
     main(["--arch", "qwen2-0.5b", "--reduced", "--requests", "2",
