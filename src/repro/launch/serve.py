@@ -10,9 +10,10 @@ continuous-batching-lite engine instead (any model family);
 paging); ``--decode-kernel fused`` runs decode attention straight from
 the KV block pool via the fused Pallas kernel.  ``--record`` keeps the
 paged engine's spans and counters (``engine.rec``) and prints their
-breakdown (:func:`repro.runtime.spans.breakdown`): device, logits copy,
-sampling and host time per step, KV and prefill fill.  The run's first
-steps compile, so serve enough requests that they do not dominate.
+breakdown (:func:`repro.runtime.spans.breakdown`): device, device draw,
+ids copy, host time and bytes copied per step, KV and prefill fill.  The
+run's first steps compile, so serve enough requests that they do not
+dominate.
 """
 from __future__ import annotations
 

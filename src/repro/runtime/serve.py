@@ -19,9 +19,11 @@ The production engine (DESIGN.md 13).  ``ServeEngine`` replaces the seed's
   ``max_context``, per-request queue deadlines, FIFO by arrival) and
   per-request latency stats (queue_s, prefill_s, first_token_s, decode
   tokens/s);
-* a vectorized counted-PRNG sampler: one jitted Gumbel-argmax draw keyed on
-  (seed, rid, token index), so sampled streams are reproducible across runs
-  AND across batch compositions;
+* sampling on the device: each dispatch's f32 logits stay where they are
+  made and one small jitted program draws every row's token there (greedy
+  argmax, or a counted-PRNG Gumbel-argmax keyed on (seed, rid, token
+  index), so sampled streams are reproducible across runs AND across batch
+  compositions); only the (rows,) int32 token ids cross to the host;
 * optional ``shard_map`` data parallelism over the decode step (slots
   sharded across mesh devices, params replicated — the eval-layer idiom)
   OR tensor parallelism (``tensor_parallel=True``: heads / FFN columns
@@ -41,8 +43,8 @@ The production engine (DESIGN.md 13).  ``ServeEngine`` replaces the seed's
   always-on decision log ``events``, and, while ``engine.rec.on`` is set,
   the spans of every step (:data:`SPANS`) and of every request
   (:data:`REQUEST_SPANS`) and counters of its work.  While recording, each
-  dispatch waits for the device before the logits' copy, so the two are
-  timed apart.
+  dispatch waits for the device before the draw, and for the draw before
+  the ids' copy, so the three are timed apart.
 
 With ``quantized=True`` the matmul weights serve as int8-PoT (repro.quant);
 dequantization happens INSIDE the jitted dispatches so the resident bytes
@@ -70,8 +72,9 @@ __all__ = ["ServeEngine", "ReferenceEngine", "Request", "summarize",
 
 #: the spans of one engine step: ``serve.step`` and its children, each also
 #: a ``jax.profiler.TraceAnnotation``.  ``*.device`` runs from the dispatch
-#: to the logits being ready, ``*.to_host`` is their copy to the host,
-#: ``*.emit`` the token loop with its callbacks and releases.
+#: to the logits being ready, ``*.sample`` is the device draw of the token
+#: ids from them, ``*.to_host`` the ids' copy to the host, ``*.emit`` the
+#: token loop with its callbacks and releases.
 SPANS = ("serve.step", "serve.expire", "serve.assign",
          "prefill.inputs", "prefill.device", "prefill.to_host",
          "prefill.sample", "prefill.emit",
@@ -293,13 +296,17 @@ class ServeEngine:
                                           nv),
                 donate_argnums=(1,))
         self._draw = jax.jit(jax.vmap(self._draw_one))
+        self._pick = jax.jit(self._pick_ids)
+        self._phase = "decode"    # the dispatch _sample draws for
+        self._out_t = 0.0         # when _sample's ids reached the host
         self.queue: deque = deque()        # FIFO admitted requests
         self.slots: dict = {}              # slot id -> _Slot
         self.rec = Recorder()              # off: the decision log only
         self._step_idx = 0
         self._seq = 0
         # prefill_s / decode_s: time.monotonic from each dispatch to the
-        # end of its logits' copy (the *.device and *.to_host spans)
+        # end of its token ids' copy (the *.device, *.sample and *.to_host
+        # spans)
         self.stats = {"prefill_tokens": 0, "decode_tokens": 0,
                       "prefill_s": 0.0, "decode_s": 0.0,
                       "prefill_dispatches": 0,
@@ -423,31 +430,60 @@ class ServeEngine:
         g = jax.random.gumbel(k, logits.shape)
         return jnp.argmax(logits / self.temperature + g)
 
-    def _sample(self, logits: np.ndarray, rids, steps) -> np.ndarray:
-        """logits: (B, V) f32; rids/steps: per-row (B,) int arrays."""
+    def _pick_ids(self, logits, rids, steps):
+        """The jitted draw: prefill's (N, V) or decode's (N, 1, V) f32
+        logits -> (N,) int32 token ids.  Greedy is ``argmax`` (ties to the
+        lowest index, as numpy's); with a temperature, ``_draw``'s
+        Gumbel-argmax keyed on each row's (rid, step)."""
+        if logits.ndim == 3:                   # decode: one position a row
+            logits = logits[:, 0]
         if self.temperature <= 0:
-            return np.argmax(logits, axis=-1)
-        return np.asarray(self._draw(jnp.asarray(rids, jnp.uint32),
-                                     jnp.asarray(steps, jnp.uint32),
-                                     jnp.asarray(logits)))
+            ids = jnp.argmax(logits, axis=-1)
+        else:
+            ids = self._draw(rids, steps, logits)
+        return ids.astype(jnp.int32)
 
-    def _dispatch(self, phase: str, fn, args):
+    def _sample(self, logits: jax.Array, rids, steps) -> np.ndarray:
+        """The host token ids of one dispatch's rows.  logits: the
+        dispatch's device logits, (N, V) or (N, 1, V) f32; rids/steps:
+        per-row (N,) int arrays (read only with a temperature).  The draw
+        runs on the device (``{phase}.sample``), then only the (N,) int32
+        ids are copied to the host (``{phase}.to_host``, its bytes counted
+        against the logits' as ``{phase}.host_bytes``)."""
+        if self.temperature > 0:
+            rids = jnp.asarray(rids, jnp.uint32)
+            steps = jnp.asarray(steps, jnp.uint32)
+        else:
+            rids = steps = None                # the argmax reads neither
+        rec, phase = self.rec, self._phase
+        with rec.span(f"{phase}.sample"):
+            ids = self._pick(logits, rids, steps)
+            if rec.on:
+                jax.block_until_ready(ids)
+        with rec.span(f"{phase}.to_host") as copy:
+            ids = np.asarray(ids)
+        self._out_t = time.monotonic() if copy is None else copy.end
+        if rec.on:
+            rec.count(f"{phase}.host_bytes", ids.nbytes, of=logits.nbytes)
+        return ids
+
+    def _dispatch(self, phase: str, fn, args, rids, steps):
         """Run the jitted ``fn`` (which returns logits and the new cache)
-        and copy the logits to the host: the host logits, and the seconds
-        from the dispatch to the copy's end.  While recording, the device
-        is waited for before the copy (``{phase}.device``), so the copy is
-        timed on its own (``{phase}.to_host``); ``np.asarray`` waits
-        anyway, so the total is the same."""
+        and draw each row's token from the logits on the device
+        (``_sample``): the host ids, and the seconds from the dispatch to
+        the ids' copy's end.  While recording, the device is waited for
+        before the draw (``{phase}.device``), so the dispatch splits into
+        device, draw and copy; the copy waits anyway, so the total is the
+        same."""
         rec = self.rec
         t0 = time.monotonic()
         with rec.span(f"{phase}.device", start=t0):
             logits, self.cache.data = fn(*args)
             if rec.on:
                 jax.block_until_ready(logits)
-        with rec.span(f"{phase}.to_host") as copy:
-            logits = np.asarray(logits)
-        t1 = time.monotonic() if copy is None else copy.end
-        return logits, t1 - t0
+        self._phase = phase
+        ids = self._sample(logits, rids, steps)
+        return ids, self._out_t - t0
 
     # ------------------------------------------------------------- frontend
     def _now(self, now):
@@ -567,6 +603,7 @@ class ServeEngine:
             slots = np.zeros(P, np.int32)
             offs = np.full(P, self.max_context, np.int32)  # dummies: drop
             nval = np.ones(P, np.int32)
+            rids = np.zeros(P, np.int64)
             ns = []
             for i, slot in enumerate(picked):
                 st = self.slots[slot]
@@ -574,6 +611,7 @@ class ServeEngine:
                 n = min(chunk, len(r.prompt) - st.n_prefilled)
                 toks[i, :n] = r.prompt[st.n_prefilled:st.n_prefilled + n]
                 slots[i], offs[i], nval[i] = slot, st.n_prefilled, n
+                rids[i] = r.rid
                 ns.append(n)
                 if self.kv_block_size:
                     self.cache.ensure(slot, st.n_prefilled + n)
@@ -584,7 +622,10 @@ class ServeEngine:
                 args += (jnp.asarray(self.cache.block_table),)
             if rec.on:
                 rec.count("prefill.tokens", sum(ns), of=P * chunk)
-        logits, dt = self._dispatch("prefill", self._prefill, args)
+        # every row's token is drawn (one fixed shape); only the rows whose
+        # prompt completes below use theirs, as token index 0
+        nxt, dt = self._dispatch("prefill", self._prefill, args, rids,
+                                 np.zeros(P, np.int64))
         self.stats["prefill_s"] += dt
         self.stats["prefill_tokens"] += int(sum(ns))
         self.stats["prefill_dispatches"] += 1
@@ -599,21 +640,16 @@ class ServeEngine:
                 done_rows.append((i, slot))
         if not done_rows:
             return
-        # prompts fully ingested: sample their first tokens from the rows'
-        # last-valid-position logits (token index 0; EOS is deliberately NOT
-        # checked here — the reference engine ignores a first-token EOS and
-        # parity pins that behavior)
-        with rec.span("prefill.sample"):
-            rows = np.array([i for i, _ in done_rows])
-            rids = np.array([self.slots[s].req.rid for _, s in done_rows])
-            nxt = self._sample(logits[rows], rids,
-                               np.zeros(len(rows), np.int64))
+        # prompts fully ingested: their first tokens, drawn from the rows'
+        # last-valid-position logits (EOS is deliberately NOT checked here —
+        # the reference engine ignores a first-token EOS and parity pins
+        # that behavior)
         with rec.span("prefill.emit"):
             t_first = self._now(now)
-            for j, (i, slot) in enumerate(done_rows):
+            for i, slot in done_rows:
                 st = self.slots[slot]
                 r = st.req
-                r.out_tokens.append(int(nxt[j]))
+                r.out_tokens.append(int(nxt[i]))
                 self._emit(r)
                 r.stats["first_token_s"] = t_first - r.arrival_s
                 st.first_s = t_first
@@ -664,13 +700,10 @@ class ServeEngine:
         if inputs is None:
             return []
         active, rids, steps, args = inputs
-        lg, dt = self._dispatch("decode", self._decode, args)
-        lg = lg[:, 0]
+        nxt, dt = self._dispatch("decode", self._decode, args, rids, steps)
         self.stats["decode_s"] += dt
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += len(active)
-        with rec.span("decode.sample"):
-            nxt = self._sample(lg, rids, steps)
         with rec.span("decode.emit"):
             t_done = self._now(now)
             finished = []

@@ -11,8 +11,8 @@ engine's own timings and a load generator share) and is also entered as a
 profiler trace's clock beside the device's operations.  Spans and counters
 go into bounded buffers that keep the newest entries and count what they
 drop (``dropped``).  :func:`breakdown` reduces what was kept over a window
-to the numbers of the engine's layers (device, logits copy, sampler, host
-loop, KV cache, prefill packing).
+to the numbers of the engine's layers (device, sampler, copy to the host,
+host loop, KV cache, prefill packing).
 """
 from __future__ import annotations
 
@@ -135,8 +135,11 @@ def breakdown(spans, counters, t0: float = -math.inf,
 
     * ``decode_device_ms`` / ``prefill_device_ms``: mean ``*.device`` span,
       from a dispatch to its logits being ready;
-    * ``logits_to_host_ms`` / ``sample_ms``: the ``*.to_host`` / ``*.sample``
+    * ``logits_to_host_ms`` / ``sample_ms``: the ``*.to_host`` (the copy of
+      a dispatch's output: its token ids) / ``*.sample`` (the device draw)
       spans summed per step, mean over the steps that have any;
+    * ``host_bytes_per_step``: the ``*.host_bytes`` counters (bytes a
+      dispatch copies off the device) summed per step, mean likewise;
     * ``step_host_share`` (%): the ``serve.step`` spans' time outside the
       ``*.device`` spans inside them, over the steps' time;
     * ``kv_block_fill`` / ``kv_blocks_held_share`` (%): mean of the
@@ -158,6 +161,13 @@ def breakdown(spans, counters, t0: float = -math.inf,
                 per[s.parent] = per.get(s.parent, 0.0) + s.end - s.start
         return 1e3 * sum(per.values()) / len(per) if per else None
 
+    def per_step_sum(*names):
+        per: dict = {}
+        for c in counters:
+            if c.name in names:
+                per[c.parent] = per.get(c.parent, 0.0) + c.value
+        return sum(per.values()) / len(per) if per else None
+
     def fill(name):
         xs = [c.value / c.of for c in counters if c.name == name and c.of]
         return 100.0 * sum(xs) / len(xs) if xs else None
@@ -178,6 +188,8 @@ def breakdown(spans, counters, t0: float = -math.inf,
         "prefill_device_ms": per_step_ms("prefill.device"),
         "logits_to_host_ms": per_step_ms("decode.to_host", "prefill.to_host"),
         "sample_ms": per_step_ms("decode.sample", "prefill.sample"),
+        "host_bytes_per_step": per_step_sum("decode.host_bytes",
+                                            "prefill.host_bytes"),
         "step_host_share": (100.0 * (step_s - device_s) / step_s
                             if step_s else None),
         "kv_block_fill": fill("kv.tokens_live"),

@@ -79,16 +79,19 @@ def test_step_spans_nest_inside_serve_step(lm):
 
 def test_dispatch_seconds_are_the_spans(lm):
     """stats["decode_s"] / ["prefill_s"] are read off the same monotonic
-    readings as the *.device and *.to_host spans: each dispatch's seconds
-    run from the device span's start to the copy span's end."""
+    readings as the *.device, *.sample and *.to_host spans: each dispatch's
+    seconds run from the device span's start, through the device draw, to
+    the ids' copy span's end."""
     eng, _ = _serve(lm, on=True)
     for phase in ("prefill", "decode"):
         dev = [s for s in eng.rec.spans if s.name == f"{phase}.device"]
+        draw = [s for s in eng.rec.spans if s.name == f"{phase}.sample"]
         host = [s for s in eng.rec.spans if s.name == f"{phase}.to_host"]
-        assert len(dev) == len(host) > 0
+        assert len(dev) == len(draw) == len(host) > 0
         total = sum(h.end - d.start for d, h in zip(dev, host))
         assert eng.stats[f"{phase}_s"] == pytest.approx(total, rel=1e-12)
-        assert all(d.end <= h.start for d, h in zip(dev, host))
+        assert all(d.end <= s.start and s.end <= h.start
+                   for d, s, h in zip(dev, draw, host))
 
 
 def test_request_spans_add_up_to_first_token(lm):
@@ -117,7 +120,9 @@ def test_counters_at_each_dispatch(lm):
     eng, reqs = _serve(lm, on=True)
     counters = list(eng.rec.counters)
     assert {c.name for c in counters} == {"kv.blocks_held", "kv.tokens_live",
-                                          "prefill.tokens"}
+                                          "prefill.tokens",
+                                          "prefill.host_bytes",
+                                          "decode.host_bytes"}
     toks = [c for c in counters if c.name == "prefill.tokens"]
     assert len(toks) == eng.stats["prefill_dispatches"]
     assert sum(c.value for c in toks) == sum(len(r.prompt) for r in reqs)
@@ -131,6 +136,16 @@ def test_counters_at_each_dispatch(lm):
     for h, c in zip(held, live):
         assert c.of == h.value * 8 and c.value <= c.of
         assert c.of - c.value < 8 * eng.max_batch
+    # each dispatch copies its rows' int32 ids off the device, of the
+    # (rows, V) f32 logits it made; counted in the step, outside its spans
+    steps = {s.seq for s in eng.rec.spans if s.name == "serve.step"}
+    vocab = lm[0].vocab
+    for phase, rows, n in (("prefill", 2, eng.stats["prefill_dispatches"]),
+                           ("decode", 3, eng.stats["decode_steps"])):
+        copied = [c for c in counters if c.name == f"{phase}.host_bytes"]
+        assert len(copied) == n
+        assert all(c.value == rows * 4 and c.of == rows * vocab * 4
+                   and c.parent in steps for c in copied)
 
 
 def test_breakdown_of_a_hand_built_record():
@@ -157,10 +172,14 @@ def test_breakdown_of_a_hand_built_record():
                 Counter("kv.blocks_held", 1.2, 4, 8, 8),
                 Counter("kv.tokens_live", 1.2, 16, 16, 8),
                 Counter("prefill.tokens", 0.2, 3, 8, 1),
-                Counter("prefill.tokens", 5.0, 8, 8, 13)]
+                Counter("prefill.tokens", 5.0, 8, 8, 13),
+                Counter("prefill.host_bytes", 0.35, 8, 1024, 1),
+                Counter("decode.host_bytes", 0.75, 12, 1536, 1),
+                Counter("decode.host_bytes", 1.45, 12, 1536, 8)]
     got = breakdown(steps, counters, 0.0, 2.0)
     want = {"decode_device_ms": 300.0, "prefill_device_ms": 200.0,
             "logits_to_host_ms": 125.0, "sample_ms": 100.0,
+            "host_bytes_per_step": (8 + 12 + 12) / 2,
             "step_host_share": 100.0 * (1.5 - 0.8) / 1.5,
             "kv_block_fill": 100.0 * (6 / 8 + 1) / 2,
             "kv_blocks_held_share": 100.0 * (2 / 8 + 4 / 8) / 2,
