@@ -1,6 +1,6 @@
 """Whole step: the FLOPs the window's prefill and decode work needs (counted
-from shapes by ``bench.flops``), over the window's seconds and the chip's
-bf16 peak."""
+from shapes by ``bench.flops``), over the window's seconds and the bf16 peak
+of the cell's chips (a share per chip: the work is shared by ``chips``)."""
 from bench import flops
 
 
@@ -10,4 +10,5 @@ def read(ctx):
     work += sum(flops.decode_flops(s, c) for _t, c, _h in rec.decode_log)
     if not work:
         return None
-    return 100.0 * work / rec.window_s / ctx.peaks["bf16_flops"]
+    return 100.0 * work / rec.window_s / (ctx.chips
+                                          * ctx.peaks["bf16_flops"])

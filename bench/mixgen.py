@@ -13,10 +13,13 @@ those of real traffic and identical in every run.  Lengths are the mix's
 distribution read at evenly spaced quantiles, the same multiset for every
 seed, in a seed-dependent order that is stratified: the sorted values are
 cut into ``BLOCK`` strata, and every run of ``BLOCK`` consecutive requests
-takes one value from each stratum, shuffled.  So any window that cuts the
-schedule holds about the same mix of short and long requests whatever the
-seed; the seed changes which request of a stretch comes first, and which
-tokens each one holds.
+takes one value from each stratum, shuffled.  The requests due in the
+mix's ``ramp_s`` and those due after it (the measured window) each take a
+multiset of their own, so the window holds the same lengths whatever the
+seed: a tail read over a few dozen requests (a p95 is then one of the
+largest two) rests on the same longest prompts in every run.  The seed
+changes which request of a stretch comes first, and which tokens each one
+holds.
 """
 from __future__ import annotations
 
@@ -85,16 +88,23 @@ def generate(mix: dict, load: dict, seed: int, seconds: float,
              vocab: int) -> list:
     """The requests of one run, by due time: ``round(rate_per_s *
     seconds)`` of them (``load`` is the cell's offered load), due over
-    ``seconds`` however fast they are served."""
+    ``seconds`` however fast they are served; those due in the first
+    ``ramp_s`` and the rest take their lengths apart."""
     if mix["arrivals"] not in ARRIVALS:
         raise ValueError(
             f"unknown arrivals {mix['arrivals']!r}: one of {sorted(ARRIVALS)}"
             ", or a generator of the mix's own in bench/traffic/<mix>.py")
     n = max(1, int(round(load["rate_per_s"] * seconds)))
     rng = np.random.default_rng(seed)
-    p = stratified(quantile_lengths(mix["prompt_len"], n), rng)
-    o = stratified(quantile_lengths(mix["output_len"], n), rng)
     due = ARRIVALS[mix["arrivals"]](n, seconds,
                                     np.random.default_rng(ARRIVAL_SEED))
+    ramp = int(np.searchsorted(due, mix.get("ramp_s", 0.0)))
+    parts = [m for m in (ramp, n - ramp) if m]
+
+    def lengths(dist):
+        return np.concatenate([stratified(quantile_lengths(dist, m), rng)
+                               for m in parts])
+    p = lengths(mix["prompt_len"])
+    o = lengths(mix["output_len"])
     return [Item(float(d), rng.integers(0, vocab, int(a), dtype=np.int32),
                  int(b)) for d, a, b in zip(due, p, o)]

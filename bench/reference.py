@@ -9,7 +9,11 @@ imports nothing of the program: the weights come from ``bench.weights`` and
 the seed, in the engine's layout, where a norm's weight is stored as its
 offset from 1.  Every matmul runs at HIGHEST precision, with no cache and no
 batching, one request at a time, attention in blocks of queries so that a
-16k-token row fits.
+16k-token row fits.  On a cell of several chips the weights come placed
+over its mesh (``bench.weights.shardings``) and XLA partitions the same
+jitted pass from that placement: each chip computes its share of the heads
+and FFN columns, and the row-parallel products are summed across chips, so
+no chip holds the whole tree and the precision is the same as on one.
 
 The comparison: for each sampled request, run the prompt followed by its
 served tokens once, and read at every served position how far the served

@@ -4,9 +4,11 @@
 
 Set-up (reported as ``setup_s``, from process start): make the weights on
 the device from the seed in one jitted call, build the serving engine with
-the cell's settings, compile its own prefill and decode programs through
-JAX's persistent cache, and serve the mix's ``ramp_s`` seconds of traffic
-(where it has one) so the window opens on a loaded engine.  Then serve the
+the cell's settings (on a cell of several chips, over a mesh of them, with
+the weights placed on it; ``bench/README.md``), compile its own prefill and
+decode programs through JAX's persistent cache, and serve the mix's
+``ramp_s`` seconds of traffic (where it has one) so the window opens on a
+loaded engine.  Then serve the
 cell's traffic for ``--seconds`` (``bench/loadgen.py``), read the device's peak memory, free the
 engine, and check what the window served against the float32 reference
 (``bench/reference.py``).  With ``--trace 0`` the result carries the cell's
@@ -45,6 +47,16 @@ if __name__ == "__main__":
 TRACE_SECONDS = 4.0        # the traced part: the window's last seconds
 SAMPLE_SALT = 0x5EED       # the correctness sample's stream, apart from traffic
 NO_CHIP = 3
+# engine settings that describe the harness, not the engine: each value the
+# harness takes, as ``build_engine``'s keywords (``eos: off`` is
+# ``eos_id=-1``, which ``build_engine`` always sets)
+HARNESS_KEYS = {
+    "sampling": {"greedy": {"temperature": 0.0}},
+    "eos": {"off": {}},
+}
+# ``build_engine``'s keywords that the harness sets from the cell itself
+SET_BY_HARNESS = frozenset({"cfg", "params", "max_batch", "max_context",
+                            "mesh", "temperature", "eos_id"})
 
 
 @dataclasses.dataclass
@@ -55,8 +67,9 @@ class Context:
     max_batch: int
     pool_blocks: int
     kv_itemsize: int
-    peaks: dict
-    trace: dict | None
+    peaks: dict            # of one chip
+    trace: dict | None     # per device: op and busy seconds are means
+    chips: int             # the cell's chips, over which the work is shared
 
 
 def _args(argv):
@@ -90,23 +103,65 @@ def _devices(chips: int, require_tpu: bool):
     return devs[:chips]
 
 
-def setup(cell, seed: int):
+def _keywords(build) -> set:
+    """The keywords ``build`` takes; where it passes the rest on
+    (``build_engine``'s ``**paged``), ``ServeEngine``'s too."""
+    import inspect
+    from repro.runtime.serve import ServeEngine
+
+    def named(fn):
+        ps = inspect.signature(fn).parameters.values()
+        return ({p.name for p in ps if p.kind in (p.POSITIONAL_OR_KEYWORD,
+                                                  p.KEYWORD_ONLY)},
+                any(p.kind is p.VAR_KEYWORD for p in ps))
+    names, passes_on = named(build)
+    return names | named(ServeEngine.__init__)[0] if passes_on else names
+
+
+def engine_kwargs(engine: dict, build) -> dict:
+    """``build``'s keywords from a cell's engine settings: the harness's
+    own keys through ``HARNESS_KEYS``, every other key as it stands.  A key
+    that neither the harness nor ``build`` knows is refused by name."""
+    known = _keywords(build) - SET_BY_HARNESS
+    kw = {}
+    for key, value in engine.items():
+        if key in HARNESS_KEYS:
+            if value not in HARNESS_KEYS[key]:
+                raise SystemExit(f"engine setting {key}={value!r}: the "
+                                 f"harness takes {sorted(HARNESS_KEYS[key])}")
+            kw.update(HARNESS_KEYS[key][value])
+        elif key in known:
+            kw[key] = value
+        else:
+            raise SystemExit(f"unknown engine setting {key!r}: neither the "
+                             f"harness nor build_engine takes it")
+    return kw
+
+
+def mesh_of(devs):
+    """A mesh over exactly the cell's chips (axis ``weights.AXIS``), or
+    None for one chip."""
+    if len(devs) == 1:
+        return None
+    import numpy as np
+    from jax.sharding import Mesh
+    from bench.weights import AXIS
+    return Mesh(np.array(devs), (AXIS,))
+
+
+def setup(cell, seed: int, mesh=None):
     """Weights from the seed, the engine, and its programs compiled."""
     import jax
     from bench import spec, weights
     from bench.loadgen import warm_up
     from repro.launch.serve import build_engine
+    kw = engine_kwargs(cell.engine, build_engine)
+    if mesh is not None:
+        kw["mesh"] = mesh
     cfg = spec.arch_config(cell.config)
-    w = jax.block_until_ready(weights.make_weights(cfg, seed))
-    eng_cfg = cell.engine
-    eng = build_engine(
-        cfg, w, max_batch=cell.cell["max_batch"],
-        max_context=cell.cell["max_context"],
-        kv_block_size=eng_cfg["kv_block_size"],
-        decode_kernel=eng_cfg["decode_kernel"],
-        prefill_batch=eng_cfg["prefill_batch"],
-        prefill_chunk=eng_cfg["prefill_chunk"],
-        quantized=eng_cfg.get("quantized", False))
+    w = jax.block_until_ready(weights.make_weights(cfg, seed, mesh))
+    eng = build_engine(cfg, w, max_batch=cell.cell["max_batch"],
+                       max_context=cell.cell["max_context"], **kw)
     warm_up(eng, cfg.vocab)
     return cfg, eng
 
@@ -127,12 +182,13 @@ def main(argv=None, *, require_tpu: bool = True, root: Path = ROOT,
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     devs = _devices(cell.workload["chips"], require_tpu)
+    mesh = mesh_of(devs)
     kind = devs[0].device_kind
     from bench import loadgen, e2e, peaks, reference, weights
     from bench import trace as trace_mod
     pk = peaks.peaks(kind) if require_tpu else peaks.PEAKS["TPU v5 lite"]
 
-    cfg, eng = setup(cell, args.seed)
+    cfg, eng = setup(cell, args.seed, mesh)
     sched = cell.schedule(args.seed, cell.ramp_s + args.seconds, cfg.vocab)
 
     tdir = tempfile.mkdtemp(prefix="bench_trace_") if args.trace else None
@@ -159,7 +215,7 @@ def main(argv=None, *, require_tpu: bool = True, root: Path = ROOT,
 
     # correctness: the reference, from the seed, after the program is freed
     t_ref = time.monotonic()
-    ref_w = weights.make_weights(cfg, args.seed)
+    ref_w = weights.make_weights(cfg, args.seed, mesh)
     picked = reference.sample(done, np.random.default_rng(
         [args.seed, SAMPLE_SALT]))
     readings = reference.compare(ref_w, cfg, picked)
@@ -174,7 +230,7 @@ def main(argv=None, *, require_tpu: bool = True, root: Path = ROOT,
     if args.trace:
         ctx = Context(rec=rec, sizes=weights.sizes(cfg), max_batch=max_batch,
                       pool_blocks=pool_blocks, kv_itemsize=kv_itemsize,
-                      peaks=pk, trace=red)
+                      peaks=pk, trace=red, chips=len(devs))
         values = {m["name"]: _reader(root, m["name"])(ctx)
                   for m in cell.metrics("per_layer")}
         units = {m["name"]: m["unit"] for m in cell.metrics("per_layer")}

@@ -6,13 +6,27 @@ ones from the seed without taking anything the program made.  Master
 weights are float32, the type the engine serves (it casts to the compute
 type inside each dispatch).  Norm scales and QKV biases are drawn too, so a
 fault in either shows in the comparison.
+
+On a cell of several chips the same call places every leaf on the cell's
+mesh where tensor-parallel serving keeps it (``shardings``), so no chip
+ever holds the whole tree; the values are the same as on one chip, bit for
+bit (JAX's partitionable threefry draws each element from its index).
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+AXIS = "model"         # the mesh axis of a cell's chips
+# decoder-layer leaves whose last dimension is a head's or an FFN column's
+# output, and those whose dim -2 is the contraction of a row-parallel
+# matmul (each chip's output a partial sum); every other leaf (embedding,
+# norms, lm_head) is replicated
+SHARD_LAST = frozenset({"wq", "wk", "wv", "bq", "bk", "bv", "wg", "wu"})
+SHARD_ROWS = frozenset({"wo", "wd"})
 
 
 def seed_key(seed: int):
@@ -69,6 +83,37 @@ def _make(key, frozen):
     }
 
 
-def make_weights(cfg, seed: int):
-    """float32 weights for ``cfg`` from ``seed``, one jitted call."""
-    return _make(seed_key(seed), tuple(sorted(sizes(cfg).items())))
+def specs(tree, axis: str = AXIS):
+    """The ``PartitionSpec`` of every leaf of a weight tree (or of its
+    shapes) on a mesh axis ``axis``."""
+    def spec(path, leaf):
+        keys = [getattr(k, "key", None) for k in path]
+        if "layers" in keys and keys[-1] in SHARD_LAST:
+            return P(*[None] * (leaf.ndim - 1), axis)
+        if "layers" in keys and keys[-1] in SHARD_ROWS:
+            return P(*[None] * (leaf.ndim - 2), axis, None)
+        return P()
+    return jax.tree_util.tree_map_with_path(spec, tree)
+
+
+def shardings(tree, mesh):
+    """``specs`` as ``NamedSharding``s on ``mesh`` (its first axis)."""
+    return jax.tree.map(lambda p: NamedSharding(mesh, p),
+                        specs(tree, mesh.axis_names[0]),
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+@lru_cache(maxsize=None)
+def _placed(mesh, frozen):
+    shapes = jax.eval_shape(_make, seed_key(0), frozen)
+    return jax.jit(_make, static_argnums=(1,),
+                   out_shardings=shardings(shapes, mesh))
+
+
+def make_weights(cfg, seed: int, mesh=None):
+    """float32 weights for ``cfg`` from ``seed``, one jitted call; with a
+    ``mesh``, each leaf made where ``shardings`` places it."""
+    key, frozen = seed_key(seed), tuple(sorted(sizes(cfg).items()))
+    if mesh is None:
+        return _make(key, frozen)
+    return _placed(mesh, frozen)(key, frozen)

@@ -86,15 +86,43 @@ def test_unknown_arrivals_are_refused():
 
 def test_every_stretch_holds_the_same_mix():
     m = mix("chat")
-    s = mixgen.generate(m, {"rate_per_s": 6.4}, 11, 30, 500)
-    n, b = len(s), mixgen.BLOCK
-    assert n == 192 and n % b == 0
-    lens = np.array([len(it.prompt) for it in s])
-    strata = np.sort(lens).reshape(b, n // b)
-    for i in range(0, n, b):
-        run = np.sort(lens[i:i + b])
-        # one value from each stratum of the sorted lengths
-        assert all(strata[j, 0] <= run[j] <= strata[j, -1] for j in range(b))
+    s = mixgen.generate(m, {"rate_per_s": 5.2}, 11, 30, 500)
+    b = mixgen.BLOCK
+    ramp = sum(it.due_s < m["ramp_s"] for it in s)
+    assert (len(s), ramp) == (156, 52)       # both parts whole runs of b
+    for part in (s[:ramp], s[ramp:]):        # the ramp, then the window
+        n = len(part)
+        lens = np.array([len(it.prompt) for it in part])
+        strata = np.sort(lens).reshape(b, n // b)
+        for i in range(0, n, b):
+            run = np.sort(lens[i:i + b])
+            # one value from each stratum of the part's sorted lengths
+            assert all(strata[j, 0] <= run[j] <= strata[j, -1]
+                       for j in range(b))
+
+
+@pytest.mark.parametrize("rate", [0.55, 2.0])
+def test_window_holds_the_same_work_every_seed(rate):
+    """The requests due after the ramp hold one multiset of lengths, the
+    same for every seed, and so do those due in it: a seed cannot move the
+    longest prompts out of the measured window."""
+    m = mix("chat")
+    runs = [mixgen.generate(m, {"rate_per_s": rate}, s, 60, 500)
+            for s in (1, 2, 2**31 + 9, 4315001507)]
+
+    def part(s, in_window):
+        its = [it for it in s if (it.due_s >= m["ramp_s"]) == in_window]
+        return (sorted(len(it.prompt) for it in its),
+                sorted(it.max_new for it in its))
+    for in_window in (False, True):
+        got = [part(s, in_window) for s in runs]
+        assert all(g == got[0] for g in got)
+    window = part(runs[0], True)
+    for lens, key in zip(window, ("prompt_len", "output_len")):
+        assert lens == list(mixgen.quantile_lengths(m[key], len(lens)))
+    assert window[0][-1] == m["prompt_len"]["max"]
+    orders = {tuple(len(it.prompt) for it in s) for s in runs}
+    assert len(orders) == len(runs)          # the seed changes the order
 
 
 def test_lognormal_median_and_uniform_range():

@@ -17,6 +17,13 @@ TINY_CONFIG = {
     "engine": {"kv_block_size": 16, "decode_kernel": "fused",
                "prefill_batch": 2, "prefill_chunk": 32},
 }
+# multi-head attention (G = 1) with QKV bias, served tensor-parallel: the
+# four-chip path, on four CPU devices (``mesh_checks.py``)
+MHA_CONFIG = dict(
+    TINY_CONFIG, name="tiny-mha", num_key_value_heads=4,
+    engine=dict(TINY_CONFIG["engine"], tensor_parallel=True,
+                sampling="greedy", eos="off"))
+TP_CELL = "tiny-mha.chat"
 TINY_MIX = {
     "arrivals": "poisson",
     "prompt_len": {"dist": "lognormal", "median": 40, "sigma": 0.7,
@@ -37,18 +44,20 @@ def e2e(name, unit, better="lower"):
             "source": "host_clock"}
 
 
-def make_tree(dst: Path, *, cell="tiny-qwen2.chat", per_layer=None) -> Path:
+def make_tree(dst: Path, *, cell="tiny-qwen2.chat", per_layer=None,
+              config=TINY_CONFIG) -> Path:
     """Copy ``bench/`` to ``dst`` and add the tiny configuration and cell."""
     shutil.copytree(REPO / "bench", dst / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    (dst / "bench/configs/tiny-qwen2.json").write_text(json.dumps(TINY_CONFIG))
+    name = config["name"]
+    (dst / f"bench/configs/{name}.json").write_text(json.dumps(config))
     (dst / "bench/traffic/tinychat.json").write_text(json.dumps(TINY_MIX))
     (dst / f"bench/cells/{cell}.json").write_text(json.dumps(TINY_CELL))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    bench["configs"] = [{"name": "tiny-qwen2", "source": "test",
-                         "file": "bench/configs/tiny-qwen2.json",
+    bench["configs"] = [{"name": name, "source": "test",
+                         "file": f"bench/configs/{name}.json",
                          "reduced": [], "why": "CPU test"}]
-    bench["workloads"] = [{"name": cell, "config": "tiny-qwen2",
+    bench["workloads"] = [{"name": cell, "config": name,
                            "traffic": "tinychat", "chips": 1, "why": "test"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
@@ -56,3 +65,17 @@ def make_tree(dst: Path, *, cell="tiny-qwen2.chat", per_layer=None) -> Path:
         bench["per_layer"] = per_layer
     (dst / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
     return dst
+
+
+def make_tp_tree(dst: Path) -> Path:
+    """The tree of ``make_tree`` with the tensor-parallel MHA cell."""
+    return make_tree(dst, cell=TP_CELL, config=MHA_CONFIG)
+
+
+def set_chips(root: Path, chips: int):
+    """Ask for ``chips`` chips in every cell of the tree at ``root``."""
+    path = Path(root) / "BENCHMARK.json"
+    bench = json.loads(path.read_text())
+    for w in bench["workloads"]:
+        w["chips"] = chips
+    path.write_text(json.dumps(bench, indent=1))
